@@ -136,10 +136,16 @@ def topk_candidates_jnp(jinst, table, k: Optional[int] = None, *,
     k_eff = M if k is None else min(int(k), M)
     cand = table[jinst.u_service]                      # [U, M]
     valid = cand >= 0
-    safe = jnp.clip(cand, 0, None)
+    safe = jnp.clip(table, 0, None)
+
+    def per_pair(a):
+        # a per-service [S, M] table, then one row per user: a gather with
+        # [U, M] indices takes minutes to compile for a TPU at U = 10⁶
+        return a[safe][jinst.u_service]
+
     q = qos_candidates(
         jinst.u_alpha, jinst.u_delta, jinst.u_share_k, jinst.u_share_w,
-        jinst.sm_acc[safe], jinst.sm_k[safe], jinst.sm_w[safe],
+        per_pair(jinst.sm_acc), per_pair(jinst.sm_k), per_pair(jinst.sm_w),
         valid.astype(jnp.float32), delta_max=float(jinst.delta_max),
         use_kernel=use_kernel)
     q = jnp.where(valid, q, -1.0)                      # pad rows sort last

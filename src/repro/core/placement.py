@@ -33,7 +33,7 @@ import numpy as np
 
 from .instance import PIESInstance
 from .qos import qos_matrix_np, eligibility_np
-from .scheduling import oms_np, sigma_np
+from .scheduling import oms_np, sigma_np, user_sum
 
 __all__ = [
     "FEASIBILITY_TOL",
@@ -318,7 +318,7 @@ def _agp_one_edge(Q, umask, sm_r, R_e, max_iters):
         x_e, best, remaining, it, done = state
         feasible = (~x_e) & (sm_r <= remaining + FEASIBILITY_TOL)
         any_feasible = feasible.any()
-        gains = jnp.maximum(Qe - best[:, None], 0.0).sum(axis=0)
+        gains = user_sum(jnp.maximum(Qe - best[:, None], 0.0))
         gains = jnp.where(feasible, gains, -jnp.inf)
         p_star = jnp.argmax(gains)
         do = any_feasible & ~done
@@ -373,7 +373,7 @@ def _egp_one_edge(Q, umask, sm_service, sm_r, R_e, relevant, max_iters):
         # lines 15–16: re-score unconsidered siblings of s* over unsatisfied
         q_star = Qe[:, p_star]
         unsat = (umask > 0) & ~satisfied
-        diff = jnp.where(unsat[:, None], Q - q_star[:, None], 0.0).sum(axis=0)
+        diff = user_sum(jnp.where(unsat[:, None], Q - q_star[:, None], 0.0))
         sib = (sm_service == sm_service[p_star]) & ~considered \
             & (jnp.arange(P) != p_star) & relevant
         v = jnp.where(place & sib, diff, v)
@@ -386,7 +386,7 @@ def _egp_one_edge(Q, umask, sm_service, sm_r, R_e, relevant, max_iters):
             | (it >= max_iters)
         return x_e, v, considered, satisfied, remaining, it, done
 
-    v0 = Qe.sum(axis=0)
+    v0 = user_sum(Qe)
     init = (jnp.zeros(P, bool), v0, jnp.zeros(P, bool), jnp.zeros(U, bool),
             R_e.astype(jnp.float32), jnp.int32(0), jnp.bool_(False))
     x_e, *_ = jax.lax.while_loop(cond, body, init)
@@ -455,10 +455,14 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
     E = R.shape[0]
     NEG = jnp.float32(-1e30)
 
-    valid = cand_idx >= 0
+    # Pairs are held candidate-major, [K, U], so that U lies along the
+    # TPU's 128-lane axis. Held [U, K], every gather and scatter over them
+    # pads K to 128 lanes and takes minutes to compile at U = 10⁶.
+    idx_t = cand_idx.T
+    valid = idx_t >= 0
     # Sentinel column P absorbs scatters from padded candidate slots.
-    col = jnp.where(valid, cand_idx, P).astype(jnp.int32)
-    qpair = jnp.where(valid, cand_q, 0.0).astype(jnp.float32)
+    col = jnp.where(valid, idx_t, P).astype(jnp.int32)
+    qpair = jnp.where(valid, cand_q.T, 0.0).astype(jnp.float32)
     erow = u_edge.astype(jnp.int32)
     sm_r = sm_r.astype(jnp.float32)
     p_arange = jnp.arange(P)
@@ -467,7 +471,7 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
     def scatter_ep(w):
         """Σ over (user, candidate) pairs into the [E, P] model grid."""
         out = jnp.zeros((E, P + 1), jnp.float32)
-        out = out.at[erow[:, None], col].add(w)
+        out = out.at[erow[None, :], col].add(w)
         return out[:, :P]
 
     relevant = scatter_ep(valid.astype(jnp.float32)) > 0.0  # [E, P]
@@ -506,7 +510,7 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
         pstar_u = p_star[erow]                            # [U] p* of u's edge
         place_u = place[erow]
         # Q(u, s_u, m*) per user — 0 unless p* is one of u's candidates.
-        qstar_u = jnp.where(col == pstar_u[:, None], qpair, 0.0).sum(axis=1)
+        qstar_u = jnp.where(col == pstar_u[None, :], qpair, 0.0).sum(axis=0)
 
         if with_trace:
             # exact marginal per placed pick, booked before best_u moves
@@ -530,8 +534,8 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
             # of s*. O(U·k) pair scatter — only run when something placed.
             v, satisfied = arg
             unsat_u = place_u & ~satisfied
-            w = jnp.where(unsat_u[:, None] & valid,
-                          qpair - qstar_u[:, None], 0.0)
+            w = jnp.where(unsat_u[None, :] & valid,
+                          qpair - qstar_u[None, :], 0.0)
             diff = scatter_ep(w)
             sib = (sm_service[None, :] == sm_service[p_star][:, None]) \
                 & ~considered & (p_arange[None, :] != p_star[:, None]) \
@@ -585,10 +589,10 @@ def sigma_sparse_jnp(cand_idx, cand_q, u_edge, x):
     every eligible implementation (``k ≥ M``)."""
     import jax.numpy as jnp
 
-    valid = cand_idx >= 0
-    safe = jnp.clip(cand_idx, 0, None)
-    placed = x[u_edge[:, None], safe] & valid
-    return jnp.where(placed, cand_q, 0.0).max(axis=1).sum()
+    idx_t = cand_idx.T            # [K, U]: see egp_place_sparse_jax
+    valid = idx_t >= 0
+    placed = x[u_edge[None, :], jnp.clip(idx_t, 0, None)] & valid
+    return jnp.where(placed, cand_q.T, 0.0).max(axis=0).sum()
 
 
 def place_and_schedule(inst: PIESInstance, algo: str = "egp", seed: int = 0,

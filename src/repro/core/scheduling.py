@@ -21,6 +21,7 @@ __all__ = [
     "schedule_value_np",
     "oms_jnp",
     "sigma_jnp",
+    "user_sum",
 ]
 
 
@@ -96,9 +97,30 @@ def oms_jnp(Q, elig, u_edge, x):
     return jnp.where(served, y, -1), qos
 
 
+def user_sum(a):
+    """Σ over the leading (user) axis of ``a`` in one fixed order.
+
+    A reduce leaves its order to the compiler, and on a TPU that order
+    follows the layout chosen for the whole batch: the same instance then
+    sums differently in a vmapped batch of 5 than in one of 2. This
+    pairwise tree of elementwise adds is evaluated as written, so an
+    item's value does not depend on the batch or device count it runs
+    with (the sweep's resume, re-chunk and shard_map byte-identity)."""
+    import jax.numpy as jnp
+
+    n = a.shape[0]
+    size = 1 << max(n - 1, 0).bit_length()
+    if size != n:
+        a = jnp.concatenate([a, jnp.zeros((size - n,) + a.shape[1:], a.dtype)])
+    while a.shape[0] > 1:
+        half = a.shape[0] // 2
+        a = a[:half] + a[half:]
+    return a[0]
+
+
 def sigma_jnp(Q, elig, u_edge, x):
     """Eq. (9) as a jnp scalar."""
     import jax.numpy as jnp
 
     ok = elig & x[u_edge]
-    return jnp.where(ok, Q, 0.0).max(axis=1).sum()
+    return user_sum(jnp.where(ok, Q, 0.0).max(axis=1))

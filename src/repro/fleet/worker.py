@@ -32,7 +32,7 @@ import sys
 import threading
 import time
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from repro import obs
 from repro.sweeps.spec import SweepSpec
@@ -260,6 +260,23 @@ def _worker_loop(queue: LeaseQueue, spec: SweepSpec, store_dir: Path,
     return summary
 
 
+def worker_devices(env: Dict[str, str]) -> Tuple[str, int]:
+    """``(platform, device count)`` that a worker started with ``env``
+    would see. Asked of a short-lived child, so this process never takes
+    a chip that its workers need."""
+    if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return "cpu", 0
+    probe = subprocess.run(
+        [sys.executable, "-c", "import jax; d = jax.devices(); "
+                               "print(d[0].platform, len(d))"],
+        env=env, capture_output=True, text=True, timeout=300)
+    if probe.returncode != 0:
+        raise RuntimeError(f"cannot tell which devices fleet workers would "
+                           f"use:\n{probe.stderr[-2000:]}")
+    platform, count = probe.stdout.split()[-2:]
+    return platform, int(count)
+
+
 def spawn_local_workers(fleet_root: os.PathLike | str, n: int, *,
                         ttl: float = DEFAULT_TTL_S,
                         max_tasks: Optional[int] = None,
@@ -270,7 +287,13 @@ def spawn_local_workers(fleet_root: os.PathLike | str, n: int, *,
     against ``fleet_root`` — the ``--fleet N`` convenience path. The
     caller waits on the returned processes and then merges. ``silence``
     drops worker stdout/stderr entirely (benchmarks emitting structured
-    output)."""
+    output).
+
+    One process holds an accelerator at a time. Workers of a host-only
+    fleet (serving sweeps, host algorithms) run with
+    ``JAX_PLATFORMS=cpu``. A fleet with device work on an accelerator
+    host runs one worker, which drives every chip through the sweep's
+    mesh; asking for more raises ``RuntimeError``."""
     import repro
 
     env = dict(os.environ)
@@ -278,6 +301,17 @@ def spawn_local_workers(fleet_root: os.PathLike | str, n: int, *,
     # exists where __file__ may be None
     pkg_root = str(Path(list(repro.__path__)[0]).resolve().parent)
     env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
+    spec = load_fleet_spec(fleet_root)
+    if not any(spec.executor_of(a) == "accel" for a in spec.algos):
+        env["JAX_PLATFORMS"] = "cpu"
+    elif int(n) > 1:
+        platform, count = worker_devices(env)
+        if platform != "cpu":
+            raise RuntimeError(
+                f"a local fleet of {n} workers would share {count} "
+                f"{platform} device(s), and one process holds a chip at a "
+                f"time: run one worker (--fleet 1), which shards the sweep "
+                f"over every chip, or set JAX_PLATFORMS=cpu")
     sink = subprocess.DEVNULL if silence else None
     procs = []
     for i in range(int(n)):

@@ -21,7 +21,7 @@ import json
 import sys
 from typing import List, Optional
 
-__all__ = ["main"]
+__all__ = ["main", "replay_live"]
 
 
 def _hconfig(args: argparse.Namespace):
@@ -137,14 +137,12 @@ def _cmd_loadgen(args: argparse.Namespace) -> int:
     return 0
 
 
-def _cmd_replay(args: argparse.Namespace) -> int:
-    from repro.serving.horizon import run_horizon
-    from .control import result_digest
+def replay_live(hconfig):
+    """The live half of the replay check: the seeded trace through the
+    virtual-clock gateway, in-process. Returns its ``HorizonResult``."""
     from .loadgen import run_loadgen
     from .server import Gateway, GatewayConfig
 
-    hconfig = _hconfig(args)
-    save_v3 = _enable_v3(args)
     gw = Gateway(GatewayConfig(horizon=hconfig, mode="virtual"))
 
     async def _replay():
@@ -155,7 +153,16 @@ def _cmd_replay(args: argparse.Namespace) -> int:
         await run_loadgen(send, hconfig, wall=False)
         return await task
 
-    live = asyncio.run(_replay())
+    return asyncio.run(_replay())
+
+
+def _cmd_replay(args: argparse.Namespace) -> int:
+    from repro.serving.horizon import run_horizon
+    from .control import result_digest
+
+    hconfig = _hconfig(args)
+    save_v3 = _enable_v3(args)
+    live = replay_live(hconfig)
     save_v3()   # live-run traces only — the offline half runs untraced
     offline = run_horizon(hconfig)
     d_live, d_off = result_digest(live), result_digest(offline)

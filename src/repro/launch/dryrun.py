@@ -145,11 +145,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, save: bool = True,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
-        # cost_analysis() returns a dict on recent jax, [dict] on older
-        cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        cost = dict(cost)
+        cost = dict(compiled.cost_analysis() or {})
         mem = memory_summary(compiled)
         hlo = compiled.as_text()
         colls = collective_stats(hlo)

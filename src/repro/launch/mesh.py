@@ -12,6 +12,15 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 
+def _auto(n_axes: int):
+    """``Auto`` axis types: the model code places arrays with
+    ``with_sharding_constraint``, which ``make_mesh``'s default
+    ``Explicit`` axes refuse."""
+    from jax.sharding import AxisType
+
+    return (AxisType.Auto,) * n_axes
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """The target mesh: one v5e pod = (data=16, model=16) = 256 chips;
     multi-pod = (pod=2, data=16, model=16) = 512 chips with pure-DP across
@@ -21,7 +30,7 @@ def make_production_mesh(*, multi_pod: bool = False):
 
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model: Optional[int] = None):
@@ -36,7 +45,8 @@ def make_host_mesh(model: Optional[int] = None):
             f"model-parallel degree must be a positive divisor of the "
             f"{n} available device(s); pick a divisor of {n} or use "
             f"make_sweep_mesh() for 1-D batch sharding")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def make_sweep_mesh(n_items: Optional[int] = None):
@@ -52,7 +62,7 @@ def make_sweep_mesh(n_items: Optional[int] = None):
 
     n = len(jax.devices())
     d = n if n_items is None else max(1, min(int(n_items), n))
-    return jax.make_mesh((d,), ("data",))
+    return jax.make_mesh((d,), ("data",), axis_types=_auto(1))
 
 
 def batch_axes(multi_pod: bool) -> Tuple[str, ...]:

@@ -452,8 +452,6 @@ def sp_qkv(ctx: MeshContext, cfg: ModelConfig, x, wq, wk, wv):
     """x: [B, S, D] seq-sharded → (q, k, v) head-sharded. The seq
     all-gather lives inside the differentiated region, so its transpose is
     a reduce-scatter (vs the baseline's full dx all-reduce)."""
-    from jax.experimental.shard_map import shard_map
-
     dt = jnp.dtype(cfg.dtype)
     m, fs, b = ctx.model_axis, ctx.fsdp_axes, ctx.batch_axes
 
@@ -468,18 +466,16 @@ def sp_qkv(ctx: MeshContext, cfg: ModelConfig, x, wq, wk, wv):
         return q, k, v
 
     hspec = P(b, None, m, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(b, m, None), P(fs, m, None), P(fs, m, None),
                   P(fs, m, None)),
-        out_specs=(hspec, hspec, hspec), check_rep=False)(x, wq, wk, wv)
+        out_specs=(hspec, hspec, hspec), check_vma=False)(x, wq, wk, wv)
 
 
 def sp_out_proj(ctx: MeshContext, cfg: ModelConfig, o, wo):
     """o: [B, S, Hp, hd] head-sharded → residual delta seq-sharded via an
     explicit psum_scatter (baseline: full all-reduce + reshard)."""
-    from jax.experimental.shard_map import shard_map
-
     dt = jnp.dtype(cfg.dtype)
     m, fs, b = ctx.model_axis, ctx.fsdp_axes, ctx.batch_axes
 
@@ -488,16 +484,14 @@ def sp_out_proj(ctx: MeshContext, cfg: ModelConfig, o, wo):
         part = jnp.einsum("bshk,hkd->bsd", ol, wo_)
         return jax.lax.psum_scatter(part, m, scatter_dimension=1, tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(b, None, m, None), P(m, None, fs)),
-        out_specs=P(b, m, None), check_rep=False)(o, wo)
+        out_specs=P(b, m, None), check_vma=False)(o, wo)
 
 
 def sp_mlp(ctx: MeshContext, cfg: ModelConfig, x, wg, wu, wd):
     """Fused SP MLP: gather seq once, TP over d_ff, psum_scatter out."""
-    from jax.experimental.shard_map import shard_map
-
     dt = jnp.dtype(cfg.dtype)
     m, fs, b = ctx.model_axis, ctx.fsdp_axes, ctx.batch_axes
     act = _act(cfg.act)
@@ -512,10 +506,10 @@ def sp_mlp(ctx: MeshContext, cfg: ModelConfig, x, wg, wu, wd):
         part = jnp.einsum("bsf,fd->bsd", h, wd_)
         return jax.lax.psum_scatter(part, m, scatter_dimension=1, tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(b, m, None), P(fs, m), P(fs, m), P(m, fs)),
-        out_specs=P(b, m, None), check_rep=False)(x, wg, wu, wd)
+        out_specs=P(b, m, None), check_vma=False)(x, wg, wu, wd)
 
 
 # ===========================================================================
@@ -620,8 +614,6 @@ def moe_block(params: Params, cfg: ModelConfig, x, *, ctx: Optional[MeshContext]
             capacity)
         return out.reshape(B, S, D)
 
-    from jax.experimental.shard_map import shard_map
-
     mesh = ctx.mesh
     msize = ctx.model_size
     ep_mode = cfg.n_experts % msize == 0
@@ -660,11 +652,11 @@ def moe_block(params: Params, cfg: ModelConfig, x, *, ctx: Optional[MeshContext]
     else:
         gu_spec = P(None, fs, m)      # [E, D, F] — d_ff over model
         dn_spec = P(None, m, fs)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(bspec, P(None, None), gu_spec, gu_spec, dn_spec),
         out_specs=ospec,
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"], params["w_down"])
     return cst(ctx, out, "batch", "model" if ctx.shard_seq else None, None)
 

@@ -220,8 +220,6 @@ def embed_tokens(params: Params, cfg: ModelConfig, tokens, ctx):
     if ctx is None or small or tokens.shape[0] % ctx.data_size != 0:
         x = jnp.take(emb.astype(dt), tokens, axis=0)
     else:
-        from jax.experimental.shard_map import shard_map
-
         m, fs = ctx.model_axis, ctx.fsdp_axes
         Vp = cfg.vocab_pad
         v_local = Vp // ctx.model_size
@@ -236,11 +234,11 @@ def embed_tokens(params: Params, cfg: ModelConfig, tokens, ctx):
             out = jnp.take(table, safe, axis=0) * ok[..., None].astype(dt)
             return jax.lax.psum(out, m)
 
-        x = shard_map(
+        x = jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(P(ctx.batch_axes, None), P(m, fs)),
             out_specs=P(ctx.batch_axes, None, None),
-            check_rep=False,
+            check_vma=False,
         )(tokens, emb)
     if cfg.scale_embed:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), dt)

@@ -1,5 +1,8 @@
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 from .cli import main
 
+enable_compile_cache()
 sys.exit(main())

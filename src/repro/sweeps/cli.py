@@ -137,7 +137,7 @@ def build_spec(args: argparse.Namespace) -> SweepSpec:
     )
 
 
-def _validate(spec: SweepSpec, result) -> float:
+def max_host_diff(spec: SweepSpec, result) -> float:
     """Max |batched − host| σ over every accelerator-evaluated item.
 
     Never-computed (NaN) cells count as infinite divergence — a partial
@@ -290,7 +290,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     summary = summarize(result, ref=args.ref)
     validate_failed = False
     if args.validate:
-        worst = _validate(spec, result)
+        worst = max_host_diff(spec, result)
         summary["validate_max_abs_diff"] = worst
         validate_failed = not (worst <= VALIDATE_ATOL)  # NaN/inf fail too
 

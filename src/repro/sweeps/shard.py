@@ -17,7 +17,9 @@ The execution core behind ``python -m repro.sweeps``. For every
    or by ``shard_map(vmap(...))`` over the mesh batch axis — with input
    buffers donated on accelerator backends. The per-item results are
    bit-identical between the two paths (each item's computation is
-   independent; no cross-batch collectives exist to reassociate);
+   independent, no cross-batch collectives exist to reassociate, and
+   sums over users run in a fixed order, :func:`repro.core.scheduling
+   .user_sum`, so the batch width does not change them);
 4. results are appended to the store (npz shard + manifest line) as soon
    as the chunk completes, so a killed sweep resumes mid-group.
 
@@ -108,7 +110,6 @@ def _mesh_n_devices(mesh) -> int:
 def _sharded_evaluator(mesh, algo: str, n_services: int, max_iters: int):
     """``jit(shard_map(vmap(one)))`` over the mesh's 1-D batch axis."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from repro.workloads.batched import single_evaluator
@@ -124,8 +125,8 @@ def _sharded_evaluator(mesh, algo: str, n_services: int, max_iters: int):
         spec = PartitionSpec(tuple(a for a in mesh.axis_names
                                    if mesh.shape[a] > 1))
         one = single_evaluator(algo, n_services, max_iters)
-        fn = shard_map(jax.vmap(one), mesh=mesh, in_specs=(spec,),
-                       out_specs=(spec, spec), check_rep=False)
+        fn = jax.shard_map(jax.vmap(one), mesh=mesh, in_specs=(spec,),
+                           out_specs=(spec, spec), check_vma=False)
         donate = () if jax.default_backend() == "cpu" else (0,)
         _EVALUATOR_CACHE[key] = jax.jit(fn, donate_argnums=donate)
     return _EVALUATOR_CACHE[key]

@@ -131,7 +131,7 @@ def pareto_mask_jax(points, maximize: Sequence[bool]) -> np.ndarray:
         return np.asarray(_JAX_MASK(signed))
 
     if pts.dtype == np.float64:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64():
             return call()
     return call()
 

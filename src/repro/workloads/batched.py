@@ -67,6 +67,7 @@ __all__ = [
     "single_evaluator",
     "evaluate_batch",
     "evaluate_sparse",
+    "sparse_evaluator",
     "evaluate_host",
     "sweep",
 ]
@@ -310,7 +311,9 @@ def evaluate_batch(batch, algo: str = "egp", max_iters: int = 512):
 
 
 @functools.lru_cache(maxsize=16)
-def _sparse_evaluator(max_iters: int, use_kernel: bool):
+def sparse_evaluator(max_iters: int, use_kernel: bool):
+    """The jitted sparse EGP tick over top-k candidate pairs:
+    ``(cand_idx, cand_q, u_edge, sm_service, sm_r, R) -> (σ, x)``."""
     import jax
 
     from repro.core.placement import egp_place_sparse_jax, sigma_sparse_jnp
@@ -356,7 +359,7 @@ def evaluate_sparse(instances: Sequence[PIESInstance], algo: str = "egp",
             tracer.metrics.gauge("placement.candidate_k").set(
                 int(cand_idx.shape[1]))
         mi = int(max_iters) if max_iters is not None else inst.P + 1
-        v, x = _sparse_evaluator(mi, use_kernel)(
+        v, x = sparse_evaluator(mi, use_kernel)(
             cand_idx, cand_q, ji.u_edge, ji.sm_service, ji.sm_r, ji.R)
         values.append(float(v))
         xs.append(x)

@@ -13,7 +13,7 @@ ROOT = Path(__file__).resolve().parent.parent
 @pytest.mark.parametrize("arch,shape", [("smollm_360m", "prefill_32k")])
 def test_dryrun_cli_single_cell(arch, shape, tmp_path):
     env = {"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin",
-           "HOME": "/tmp"}
+           "HOME": "/tmp", "JAX_PLATFORMS": "cpu"}
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", arch, "--shape", shape, "--single-pod"],

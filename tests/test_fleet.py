@@ -40,6 +40,7 @@ def _spec(scenarios=("steady",), seeds=(0, 1), algos=("edf",),
 def _env():
     env = dict(os.environ)
     env["PYTHONPATH"] = str(SRC) + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # workers must never take an accelerator
     return env
 
 
@@ -166,6 +167,44 @@ def test_plan_worker_merge_single_worker_byte_identical(tmp_path):
     for key in refs.keys():
         assert merged.metrics(key) == refs.metrics(key)
         assert merged.meta(key)["fleet_worker"] == "w0"
+
+
+def _record_popen(monkeypatch):
+    """Stand in for ``subprocess.Popen`` in the worker module; returns the
+    list of environments the workers would have been started with."""
+    from repro.fleet import worker
+
+    envs = []
+    monkeypatch.setattr(worker.subprocess, "Popen",
+                        lambda cmd, env, **kw: envs.append(env))
+    return envs
+
+
+def test_host_only_fleet_workers_never_take_an_accelerator(tmp_path,
+                                                           monkeypatch):
+    from repro.fleet.worker import spawn_local_workers
+
+    plan(_spec(), tmp_path / "fleet", target_store=tmp_path / "store")
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    envs = _record_popen(monkeypatch)
+    spawn_local_workers(tmp_path / "fleet", 3)
+    assert [e["JAX_PLATFORMS"] for e in envs] == ["cpu"] * 3
+
+
+def test_device_fleet_refuses_more_workers_than_one_process(tmp_path,
+                                                           monkeypatch):
+    from repro.fleet import worker
+
+    spec = SweepSpec(scenarios=("steady",), seeds=(0, 1), n_ticks=1,
+                     algos=("egp",))
+    plan(spec, tmp_path / "fleet", target_store=tmp_path / "store")
+    monkeypatch.setattr(worker, "worker_devices", lambda env: ("tpu", 1))
+    envs = _record_popen(monkeypatch)
+    with pytest.raises(RuntimeError, match="one process holds a chip"):
+        worker.spawn_local_workers(tmp_path / "fleet", 2)
+    assert envs == []
+    worker.spawn_local_workers(tmp_path / "fleet", 1)  # one worker may
+    assert len(envs) == 1
 
 
 def test_plan_skips_completed_seeds_and_rejects_foreign_spec(tmp_path):

@@ -562,6 +562,7 @@ def test_bench_cli_rows_compare_trajectory(tmp_path):
     records. Uses the instant roofline_table row."""
     env = dict(os.environ)
     env["PYTHONPATH"] = str(SRC) + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # the child must never take an accelerator
     repo = Path(__file__).resolve().parents[1]
     new_json = tmp_path / "new.json"
     traj = tmp_path / "traj.jsonl"

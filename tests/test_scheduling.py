@@ -2,6 +2,7 @@
 import itertools
 
 import numpy as np
+import pytest
 from hypothesis_compat import given, settings, st
 
 from repro.core import (
@@ -96,3 +97,36 @@ def test_oms_jnp_matches_np():
     # schedules may differ only on exact ties; values per user must match
     per_user_np = np.where(y_np >= 0, Q[np.arange(inst.U), np.maximum(y_np, 0)], 0.0)
     np.testing.assert_allclose(np.asarray(qos_j), per_user_np, atol=1e-5)
+
+
+def _pairwise_f32(a):
+    """The order ``user_sum`` documents, in NumPy float32."""
+    a = np.asarray(a, np.float32)
+    size = 1
+    while size < a.shape[0]:
+        size *= 2
+    a = np.concatenate([a, np.zeros((size - a.shape[0],) + a.shape[1:],
+                                    np.float32)])
+    while a.shape[0] > 1:
+        a = a[: a.shape[0] // 2] + a[a.shape[0] // 2:]
+    return a[0]
+
+
+@pytest.mark.parametrize("n", [0, 1, 2, 7, 64, 97])
+def test_user_sum_has_one_fixed_order_at_any_batch_width(n):
+    """Bit for bit the documented pairwise tree, alone and inside vmapped
+    batches of any width: the order never depends on the batch."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.scheduling import user_sum
+
+    rng = np.random.default_rng(n)
+    rows = rng.standard_normal((5, n, 3)).astype(np.float32) * 1e3
+    want = np.stack([_pairwise_f32(r) for r in rows])
+    for width in (1, 2, 5):
+        got = np.asarray(jax.jit(jax.vmap(user_sum))(jnp.asarray(
+            rows[:width])))
+        np.testing.assert_array_equal(got, want[:width])
+    np.testing.assert_allclose(want, rows.astype(np.float64).sum(axis=1),
+                               rtol=1e-5, atol=1e-2)

@@ -372,7 +372,7 @@ def test_sharded_equals_vmap_equals_host_on_uneven_chunks(tmp_path):
     script.write_text(_SHARD_SCRIPT)
     env = dict(os.environ)
     env["PYTHONPATH"] = str(SRC) + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # the child must never take an accelerator
     proc = subprocess.run([sys.executable, str(script)], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]
