@@ -34,8 +34,8 @@ def run(verbose: bool = True):
     rows.append(("qos_matrix_jnp_jit", t_jnp, f"{UP/t_jnp:.0f} pairs/us"))
 
     # --- qos matrix Pallas dispatcher, timed via repro.obs span durations ---
-    # block_until_ready inside the span: JAX dispatch is async, so the
-    # ops-level kernel.qos_matrix span alone covers dispatch, not compute
+    # block_until_ready inside the span: JAX dispatch is async, so a span
+    # around the call alone would cover dispatch, not compute
     from repro import obs
     from repro.obs import trace as _obs_trace
     from repro.kernels.qos_matrix.ops import qos_matrix_from_instance

@@ -434,6 +434,14 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
     (:mod:`repro.kernels.qos_matrix`); the default uses the identical jnp
     reduction (interpret-mode Pallas inside a while_loop is slow on CPU).
 
+    The program's phases carry ``jax.named_scope`` tags, so a profiler
+    trace (or the compiled HLO's ``op_name`` metadata) attributes device
+    time to them: ``greedy.init`` (the pair layout and the initial benefit
+    scatters), and per iteration ``greedy.pick`` (lines 11–14 and the
+    per-user gathers of the pick), ``greedy.rescore`` (the ``lax.cond`` of
+    lines 15–19) and ``greedy.stop_test`` (line 17's ``considered`` update,
+    the per-edge unsatisfied count and line 20).
+
     ``with_trace=True`` additionally returns a per-iteration decision
     trace for the observability ledger: ``[max_iters, E]`` arrays of the
     pick (``impl``, −1 where an edge had no candidate / was done), its
@@ -445,8 +453,21 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
     the trace arrays are write-only extensions of the loop carry.
 
     Returns ``x [E, P]`` bool (or ``(x, trace_dict)`` with
-    ``with_trace=True``).
+    ``with_trace=True``; the trace also holds the loop's counts
+    ``n_iters`` and ``n_rescores``).
     """
+    x, info = _egp_place_sparse(
+        cand_idx, cand_q, u_edge, sm_service, sm_r, R, max_iters=max_iters,
+        use_kernel=use_kernel, with_trace=with_trace)
+    return (x, info) if with_trace else x
+
+
+def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
+                      max_iters: int, use_kernel: bool, with_trace: bool):
+    """:func:`egp_place_sparse_jax`'s program, returning ``(x, info)``:
+    ``info["n_iters"]`` (iterations run) and ``info["n_rescores"]``
+    (iterations in which some edge placed and the re-score ran), int32
+    scalars, plus the decision trace's arrays with ``with_trace``."""
     import jax
     import jax.numpy as jnp
 
@@ -455,27 +476,28 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
     E = R.shape[0]
     NEG = jnp.float32(-1e30)
 
-    # Pairs are held candidate-major, [K, U], so that U lies along the
-    # TPU's 128-lane axis. Held [U, K], every gather and scatter over them
-    # pads K to 128 lanes and takes minutes to compile at U = 10⁶.
-    idx_t = cand_idx.T
-    valid = idx_t >= 0
-    # Sentinel column P absorbs scatters from padded candidate slots.
-    col = jnp.where(valid, idx_t, P).astype(jnp.int32)
-    qpair = jnp.where(valid, cand_q.T, 0.0).astype(jnp.float32)
-    erow = u_edge.astype(jnp.int32)
-    sm_r = sm_r.astype(jnp.float32)
-    p_arange = jnp.arange(P)
-    e_arange = jnp.arange(E)
-
     def scatter_ep(w):
         """Σ over (user, candidate) pairs into the [E, P] model grid."""
         out = jnp.zeros((E, P + 1), jnp.float32)
         out = out.at[erow[None, :], col].add(w)
         return out[:, :P]
 
-    relevant = scatter_ep(valid.astype(jnp.float32)) > 0.0  # [E, P]
-    v0 = scatter_ep(qpair)  # lines 3–6: v[(s,m)] = Σ_{u∈U_e} Q(u,s_u,m)
+    with jax.named_scope("greedy.init"):
+        # Pairs are held candidate-major, [K, U], so that U lies along the
+        # TPU's 128-lane axis. Held [U, K], every gather and scatter over
+        # them pads K to 128 lanes and takes minutes to compile at U = 10⁶.
+        idx_t = cand_idx.T
+        valid = idx_t >= 0
+        # Sentinel column P absorbs scatters from padded candidate slots.
+        col = jnp.where(valid, idx_t, P).astype(jnp.int32)
+        qpair = jnp.where(valid, cand_q.T, 0.0).astype(jnp.float32)
+        erow = u_edge.astype(jnp.int32)
+        sm_r = sm_r.astype(jnp.float32)
+        p_arange = jnp.arange(P)
+        e_arange = jnp.arange(E)
+        relevant = scatter_ep(valid.astype(jnp.float32)) > 0.0  # [E, P]
+        # lines 3–6: v[(s,m)] = Σ_{u∈U_e} Q(u,s_u,m)
+        v0 = scatter_ep(qpair)
 
     def masked_argmax(v, cand):
         if use_kernel:
@@ -488,46 +510,50 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
     def cond(state):
         # `it` and `done` sit at fixed positions in both carry layouts
         # (with and without the trace extension)
-        done, it = state[-1], state[5]
-        return (~done.all()) & (it < max_iters)
+        with jax.named_scope("greedy.stop_test"):
+            done, it = state[-1], state[5]
+            return (~done.all()) & (it < max_iters)
 
     def body(state):
         if with_trace:
-            (x, v, considered, satisfied, remaining, it,
+            (x, v, considered, satisfied, remaining, it, n_rescore,
              best_u, tr, done) = state
         else:
-            x, v, considered, satisfied, remaining, it, done = state
-        cand = relevant & ~considered
-        any_cand = cand.any(axis=1)                       # [E]
-        p_star = masked_argmax(v, cand)                   # [E] line 11
-        fits = sm_r[p_star] <= remaining + FEASIBILITY_TOL
-        place = fits & any_cand & ~done                   # lines 12–14
-        active = any_cand & ~done     # edges actually picking this iter
-        benefit = jnp.take_along_axis(v, p_star[:, None], 1)[:, 0]
-        x = x.at[e_arange, p_star].set(x[e_arange, p_star] | place)
-        remaining = remaining - jnp.where(place, sm_r[p_star], 0.0)
+            x, v, considered, satisfied, remaining, it, n_rescore, done = \
+                state
+        with jax.named_scope("greedy.pick"):
+            cand = relevant & ~considered
+            any_cand = cand.any(axis=1)                       # [E]
+            p_star = masked_argmax(v, cand)                   # [E] line 11
+            fits = sm_r[p_star] <= remaining + FEASIBILITY_TOL
+            place = fits & any_cand & ~done                   # lines 12–14
+            active = any_cand & ~done  # edges actually picking this iter
+            benefit = jnp.take_along_axis(v, p_star[:, None], 1)[:, 0]
+            x = x.at[e_arange, p_star].set(x[e_arange, p_star] | place)
+            remaining = remaining - jnp.where(place, sm_r[p_star], 0.0)
 
-        pstar_u = p_star[erow]                            # [U] p* of u's edge
-        place_u = place[erow]
-        # Q(u, s_u, m*) per user — 0 unless p* is one of u's candidates.
-        qstar_u = jnp.where(col == pstar_u[None, :], qpair, 0.0).sum(axis=0)
+            pstar_u = p_star[erow]                        # [U] p* of u's edge
+            place_u = place[erow]
+            # Q(u, s_u, m*) per user — 0 unless p* is one of u's candidates.
+            qstar_u = jnp.where(col == pstar_u[None, :], qpair,
+                                0.0).sum(axis=0)
 
-        if with_trace:
-            # exact marginal per placed pick, booked before best_u moves
-            imp_u = jnp.where(place_u,
-                              jnp.maximum(qstar_u - best_u, 0.0), 0.0)
-            gain_e = jnp.zeros(E, jnp.float32).at[erow].add(imp_u)
-            best_u = jnp.where(place_u, jnp.maximum(best_u, qstar_u),
-                               best_u)
-            t_pick, t_place, t_ben, t_gain, t_rem, t_ncand = tr
-            tr = (
-                t_pick.at[it].set(jnp.where(active, p_star, -1)),
-                t_place.at[it].set(place),
-                t_ben.at[it].set(jnp.where(active, benefit, 0.0)),
-                t_gain.at[it].set(gain_e),
-                t_rem.at[it].set(remaining),
-                t_ncand.at[it].set(cand.sum(axis=1).astype(jnp.int32)),
-            )
+            if with_trace:
+                # exact marginal per placed pick, booked before best_u moves
+                imp_u = jnp.where(place_u,
+                                  jnp.maximum(qstar_u - best_u, 0.0), 0.0)
+                gain_e = jnp.zeros(E, jnp.float32).at[erow].add(imp_u)
+                best_u = jnp.where(place_u, jnp.maximum(best_u, qstar_u),
+                                   best_u)
+                t_pick, t_place, t_ben, t_gain, t_rem, t_ncand = tr
+                tr = (
+                    t_pick.at[it].set(jnp.where(active, p_star, -1)),
+                    t_place.at[it].set(place),
+                    t_ben.at[it].set(jnp.where(active, benefit, 0.0)),
+                    t_gain.at[it].set(gain_e),
+                    t_rem.at[it].set(remaining),
+                    t_ncand.at[it].set(cand.sum(axis=1).astype(jnp.int32)),
+                )
 
         def rescore(arg):
             # lines 15–16: v[p] = Σ_unsat (Q[u,p] − Q[u,p*]) for siblings
@@ -545,54 +571,66 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
             satisfied = satisfied | (place_u & (qstar_u >= 1.0 - 1e-6))
             return v, satisfied
 
-        v, satisfied = jax.lax.cond(place.any(), rescore, lambda a: a,
-                                    (v, satisfied))
-        considered = considered.at[e_arange, p_star].set(
-            considered[e_arange, p_star] | any_cand)      # line 17
-        n_unsat = jnp.zeros(E, jnp.int32).at[erow].add(
-            (~satisfied).astype(jnp.int32))
-        all_sat = n_unsat == 0
-        all_cons = (considered | ~relevant).all(axis=1)
-        # line 20 — same stop conditions (and tolerances) as _egp_one_edge
-        done = done | ~any_cand | (remaining <= 1e-6) | all_sat | all_cons
+        with jax.named_scope("greedy.rescore"):
+            placed_any = place.any()
+            v, satisfied = jax.lax.cond(placed_any, rescore, lambda a: a,
+                                        (v, satisfied))
+            n_rescore = n_rescore + placed_any.astype(jnp.int32)
+        with jax.named_scope("greedy.stop_test"):
+            considered = considered.at[e_arange, p_star].set(
+                considered[e_arange, p_star] | any_cand)  # line 17
+            n_unsat = jnp.zeros(E, jnp.int32).at[erow].add(
+                (~satisfied).astype(jnp.int32))
+            all_sat = n_unsat == 0
+            all_cons = (considered | ~relevant).all(axis=1)
+            # line 20 — same stop conditions (and tolerances) as
+            # _egp_one_edge
+            done = done | ~any_cand | (remaining <= 1e-6) | all_sat \
+                | all_cons
+            it = it + 1
         if with_trace:
-            return (x, v, considered, satisfied, remaining, it + 1,
+            return (x, v, considered, satisfied, remaining, it, n_rescore,
                     best_u, tr, done)
-        return x, v, considered, satisfied, remaining, it + 1, done
+        return x, v, considered, satisfied, remaining, it, n_rescore, done
 
-    init_core = (jnp.zeros((E, P), bool), v0, jnp.zeros((E, P), bool),
-                 jnp.zeros(U, bool), R.astype(jnp.float32), jnp.int32(0))
+    with jax.named_scope("greedy.init"):
+        init_core = (jnp.zeros((E, P), bool), v0, jnp.zeros((E, P), bool),
+                     jnp.zeros(U, bool), R.astype(jnp.float32),
+                     jnp.int32(0), jnp.int32(0))
+        if with_trace:
+            tr0 = (jnp.full((max_iters, E), -1, jnp.int32),
+                   jnp.zeros((max_iters, E), bool),
+                   jnp.zeros((max_iters, E), jnp.float32),
+                   jnp.zeros((max_iters, E), jnp.float32),
+                   jnp.zeros((max_iters, E), jnp.float32),
+                   jnp.zeros((max_iters, E), jnp.int32))
+            init = init_core + (jnp.zeros(U, jnp.float32), tr0,
+                                jnp.zeros(E, bool))
+        else:
+            init = init_core + (jnp.zeros(E, bool),)
+    out = jax.lax.while_loop(cond, body, init)
+    info = {"n_iters": out[5], "n_rescores": out[6]}
     if with_trace:
-        tr0 = (jnp.full((max_iters, E), -1, jnp.int32),
-               jnp.zeros((max_iters, E), bool),
-               jnp.zeros((max_iters, E), jnp.float32),
-               jnp.zeros((max_iters, E), jnp.float32),
-               jnp.zeros((max_iters, E), jnp.float32),
-               jnp.zeros((max_iters, E), jnp.int32))
-        init = init_core + (jnp.zeros(U, jnp.float32), tr0,
-                            jnp.zeros(E, bool))
-        out = jax.lax.while_loop(cond, body, init)
-        x, tr = out[0], out[7]
-        trace = {"pick": tr[0], "placed": tr[1], "benefit": tr[2],
-                 "gain": tr[3], "remaining": tr[4],
-                 "n_candidates": tr[5], "n_iters": out[5]}
-        return x, trace
-    init = init_core + (jnp.zeros(E, bool),)
-    x, *_ = jax.lax.while_loop(cond, body, init)
-    return x
+        tr = out[8]
+        info.update(pick=tr[0], placed=tr[1], benefit=tr[2], gain=tr[3],
+                    remaining=tr[4], n_candidates=tr[5])
+    return out[0], info
 
 
 def sigma_sparse_jnp(cand_idx, cand_q, u_edge, x):
     """σ (Eq. 9 with OMS folded in) over candidate pairs: each user gets its
     best *placed* candidate at its own edge. Exact vs
     :func:`repro.core.scheduling.sigma_jnp` when the candidate set kept
-    every eligible implementation (``k ≥ M``)."""
+    every eligible implementation (``k ≥ M``). Its ops carry the
+    ``sigma`` scope."""
+    import jax
     import jax.numpy as jnp
 
-    idx_t = cand_idx.T            # [K, U]: see egp_place_sparse_jax
-    valid = idx_t >= 0
-    placed = x[u_edge[None, :], jnp.clip(idx_t, 0, None)] & valid
-    return jnp.where(placed, cand_q.T, 0.0).max(axis=0).sum()
+    with jax.named_scope("sigma"):
+        idx_t = cand_idx.T            # [K, U]: see egp_place_sparse_jax
+        valid = idx_t >= 0
+        placed = x[u_edge[None, :], jnp.clip(idx_t, 0, None)] & valid
+        return jnp.where(placed, cand_q.T, 0.0).max(axis=0).sum()
 
 
 def place_and_schedule(inst: PIESInstance, algo: str = "egp", seed: int = 0,

@@ -5,7 +5,7 @@ import functools
 
 import jax
 
-from repro.obs import kernel_span, named_scope
+from repro.obs import named_scope
 
 from .qos_matrix import (greedy_argmax_pallas, qos_candidates_pallas,
                          qos_matrix_pallas)
@@ -63,15 +63,11 @@ def qos_matrix_from_instance(jinst, use_kernel: bool = True):
     from .qos_matrix import check_service_ids
 
     check_service_ids(jinst.u_service, jinst.sm_service)
-    # the obs span covers dispatch only (JAX is async); benchmarks that
-    # want honest kernel wall time block_until_ready inside their own span
-    with kernel_span("qos_matrix", U=int(jinst.u_alpha.shape[0]),
-                     P=int(jinst.sm_acc.shape[0]), use_kernel=use_kernel):
-        return qos_matrix(
-            jinst.u_alpha, jinst.u_delta, jinst.u_share_k, jinst.u_share_w,
-            jinst.u_service, jinst.sm_acc, jinst.sm_k, jinst.sm_w,
-            jinst.sm_service, delta_max=float(jinst.delta_max),
-            use_kernel=use_kernel)
+    return qos_matrix(
+        jinst.u_alpha, jinst.u_delta, jinst.u_share_k, jinst.u_share_w,
+        jinst.u_service, jinst.sm_acc, jinst.sm_k, jinst.sm_w,
+        jinst.sm_service, delta_max=float(jinst.delta_max),
+        use_kernel=use_kernel)
 
 
 def qos_candidates_from_instance(jinst, table, k=None, *,
@@ -81,7 +77,4 @@ def qos_candidates_from_instance(jinst, table, k=None, *,
     """
     from repro.core.candidates import topk_candidates_jnp
 
-    U = int(jinst.u_alpha.shape[0])
-    with kernel_span("qos_candidates", U=U, k=-1 if k is None else int(k),
-                     use_kernel=use_kernel):
-        return topk_candidates_jnp(jinst, table, k, use_kernel=use_kernel)
+    return topk_candidates_jnp(jinst, table, k, use_kernel=use_kernel)

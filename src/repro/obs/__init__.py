@@ -19,16 +19,20 @@ tracing changes no stored sweep byte and no ``TickReport`` field (tested
 Instrumented hot paths: :mod:`repro.serving.horizon` (per-tick
 materialize/place/route/execute spans, queue-depth + realized-QoS
 gauges, per-request latency histograms), :mod:`repro.sweeps`
-(per-chunk spans, items/s, store I/O timing), :mod:`repro.fleet`
-(worker telemetry files behind ``fleet status`` rate/ETA), and the
-Pallas kernel dispatchers (``kernel.*`` annotations).
+(per-chunk spans with their pad/dispatch/wait phases, items/s, store I/O
+timing), the sparse placement tick :func:`repro.workloads.evaluate_sparse`
+(``placement.*`` spans and the greedy's iteration and re-score counters),
+and :mod:`repro.fleet` (worker telemetry files behind ``fleet status``
+rate/ETA). Device time is attributed by ``jax.named_scope`` tags in the
+programs themselves (the kernels', the sparse greedy's ``greedy.*``
+phases, ``sigma``), which the JAX profiler's trace carries.
 """
 from .metrics import (METRICS_SCHEMA_VERSION, Counter, Gauge, Histogram,
                       MetricsRegistry)
 from .trace import (DEFAULT_CAPACITY, OBS_SCHEMA_VERSION,
                     READABLE_OBS_SCHEMAS, Tracer, count, disable, enable,
-                    enable_from_env, enabled, get_tracer, load_artifact,
-                    sample, save, span, to_chrome_trace,
+                    enable_from_env, enabled, get_tracer, last_tracer,
+                    load_artifact, sample, save, span, to_chrome_trace,
                     validate_chrome_trace)
 from .jaxprof import (have_jax_profiler, kernel_span, named_scope,
                       profile_trace)
@@ -51,7 +55,8 @@ __all__ = [
     "OBS_SCHEMA_VERSION", "METRICS_SCHEMA_VERSION", "DEFAULT_CAPACITY",
     "READABLE_OBS_SCHEMAS", "STREAM_SCHEMA_VERSION", "SLO_SCHEMA_VERSION",
     "Tracer", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "enable", "disable", "enabled", "get_tracer", "enable_from_env",
+    "enable", "disable", "enabled", "get_tracer", "last_tracer",
+    "enable_from_env",
     "span", "count", "sample", "save",
     "load_artifact", "to_chrome_trace", "validate_chrome_trace",
     "kernel_span", "named_scope", "profile_trace", "have_jax_profiler",

@@ -7,13 +7,13 @@ Two directions of the same timeline:
   ``jax.profiler.TraceAnnotation``, so the driver-level structure
   (``tick.place``, ``sweep.chunk``, ...) shows up inside the JAX/XLA
   profile next to the kernels it wraps.
-* **kernel time → obs**: :func:`kernel_span` is the host-side wrapper
-  the kernel dispatchers (``repro.kernels.qos_matrix``,
-  ``flash_attention``) use — an obs span named ``kernel.<x>`` (so the
-  Chrome-trace export carries kernel annotations on the same timeline as
-  the tick spans) plus, inside traced code, ``jax.named_scope`` tags the
-  emitted HLO so Pallas kernel time is attributable in ``jax.profiler``
-  dumps too.
+* **host work → obs**: :func:`kernel_span` is an obs span named
+  ``kernel.<x>`` for host work that stands in for a kernel (the serving
+  horizon's NumPy QoS matrix), on the same timeline as the tick spans.
+  It times the host: around an asynchronous JAX dispatch it would time
+  the enqueue, not the kernel. Device time comes from
+  :func:`named_scope`, whose ``jax.named_scope`` tags the emitted HLO so
+  kernel time is attributable in ``jax.profiler`` dumps.
 
 :func:`profile_trace` wraps ``jax.profiler.trace`` (TensorBoard /
 Perfetto-loadable ``plugins/profile`` dumps); everything degrades to a

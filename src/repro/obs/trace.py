@@ -50,6 +50,7 @@ __all__ = [
     "disable",
     "enabled",
     "get_tracer",
+    "last_tracer",
     "span",
     "count",
     "sample",
@@ -319,6 +320,7 @@ class Tracer:
 # ===========================================================================
 
 _TRACER: Optional[Tracer] = None
+_LAST: Optional[Tracer] = None
 
 
 def enable(capacity: int = DEFAULT_CAPACITY, *,
@@ -336,8 +338,10 @@ def enable(capacity: int = DEFAULT_CAPACITY, *,
 def disable() -> Optional[Tracer]:
     """Uninstall the global tracer; returns it so callers can still
     snapshot/save what was recorded."""
-    global _TRACER
+    global _TRACER, _LAST
     tr, _TRACER = _TRACER, None
+    if tr is not None:
+        _LAST = tr
     return tr
 
 
@@ -347,6 +351,14 @@ def enabled() -> bool:
 
 def get_tracer() -> Optional[Tracer]:
     return _TRACER
+
+
+def last_tracer() -> Optional[Tracer]:
+    """The tracer the latest :func:`disable` uninstalled, so that code
+    that runs after tracing stopped, and did not hold the handle, can
+    still read its counters and spans (a benchmark's metric readers run
+    after the profiler window closed)."""
+    return _LAST
 
 
 def span(name: str, **args: Any):

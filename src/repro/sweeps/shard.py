@@ -148,6 +148,11 @@ def _eval_accel_chunk(instances: List, algo: str,
     resume, re-chunk, and fleet-merge byte-identity are preserved.
     ``bucketed=False`` keeps the legacy single-envelope pad.
 
+    With a tracer enabled the chunk's host phases are spans under the
+    caller's ``sweep.chunk``: ``sweep.pad`` (bucketing, and per bucket the
+    padding and its host-to-device copy), ``sweep.dispatch`` (the jitted
+    or sharded call) and ``sweep.wait`` (the host blocks on the values).
+
     ``exec_seconds`` is the steady-state execution wall time: the first
     call per (path, shape) triggers the XLA compile, so that chunk is
     re-padded and re-run once and the re-run is what gets timed —
@@ -160,27 +165,33 @@ def _eval_accel_chunk(instances: List, algo: str,
 
     B = len(instances)
     n_dev = 1 if mesh is None else _mesh_n_devices(mesh)
-    if bucketed:
-        groups = bucket_indices(instances, cap=envelope)
-    else:
-        groups = [(tuple(envelope), list(range(B)))]
+    with obs.span("sweep.pad"):
+        if bucketed:
+            groups = bucket_indices(instances, cap=envelope)
+        else:
+            groups = [(tuple(envelope), list(range(B)))]
     path = "vmap" if n_dev <= 1 else "shard_map"
 
     def call():
         out = np.empty(B, dtype=np.float64)
         for benv, idx in groups:
-            members = [instances[i] for i in idx]
-            if n_dev > 1:
-                members = members + [members[0]] * ((-len(idx)) % n_dev)
-            batch = pad_instances(members, *benv)
-            if n_dev <= 1:
-                values, _ = evaluate_batch(batch, algo=algo,
-                                           max_iters=max_iters)
-            else:
-                fn = _sharded_evaluator(mesh, algo, batch.n_services,
-                                        max_iters)
-                values, _ = fn(batch.jax_instance)
-            out[idx] = np.asarray(values, np.float64)[:len(idx)]
+            # padding builds the bucket's device arrays: the host-to-device
+            # copy is part of the pad span
+            with obs.span("sweep.pad", items=len(idx)):
+                members = [instances[i] for i in idx]
+                if n_dev > 1:
+                    members = members + [members[0]] * ((-len(idx)) % n_dev)
+                batch = pad_instances(members, *benv)
+            with obs.span("sweep.dispatch"):
+                if n_dev <= 1:
+                    values, _ = evaluate_batch(batch, algo=algo,
+                                               max_iters=max_iters)
+                else:
+                    fn = _sharded_evaluator(mesh, algo, batch.n_services,
+                                            max_iters)
+                    values, _ = fn(batch.jax_instance)
+            with obs.span("sweep.wait"):
+                out[idx] = np.asarray(values, np.float64)[:len(idx)]
         return out
 
     t0 = time.perf_counter()

@@ -313,16 +313,19 @@ def evaluate_batch(batch, algo: str = "egp", max_iters: int = 512):
 @functools.lru_cache(maxsize=16)
 def sparse_evaluator(max_iters: int, use_kernel: bool):
     """The jitted sparse EGP tick over top-k candidate pairs:
-    ``(cand_idx, cand_q, u_edge, sm_service, sm_r, R) -> (σ, x)``."""
+    ``(cand_idx, cand_q, u_edge, sm_service, sm_r, R) -> (σ, x, n_iters,
+    n_rescores)``, the last two the greedy loop's counts (int32 scalars:
+    iterations, and iterations that ran the re-score)."""
     import jax
 
-    from repro.core.placement import egp_place_sparse_jax, sigma_sparse_jnp
+    from repro.core.placement import _egp_place_sparse, sigma_sparse_jnp
 
     def run(cand_idx, cand_q, u_edge, sm_service, sm_r, R):
-        x = egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service,
-                                 sm_r, R, max_iters=max_iters,
-                                 use_kernel=use_kernel)
-        return sigma_sparse_jnp(cand_idx, cand_q, u_edge, x), x
+        x, info = _egp_place_sparse(
+            cand_idx, cand_q, u_edge, sm_service, sm_r, R,
+            max_iters=max_iters, use_kernel=use_kernel, with_trace=False)
+        return (sigma_sparse_jnp(cand_idx, cand_q, u_edge, x), x,
+                info["n_iters"], info["n_rescores"])
 
     return jax.jit(run)
 
@@ -339,8 +342,16 @@ def evaluate_sparse(instances: Sequence[PIESInstance], algo: str = "egp",
     ``max_iters=None`` uses ``P + 1`` (an edge never picks more than P
     models, so the greedy runs to its natural stop). ``use_kernel`` routes
     segmented QoS and the per-edge argmax through the Pallas kernels.
-    The effective ``k`` is published on the ``placement.candidate_k``
-    gauge.
+
+    With a tracer enabled each instance's host phases are spans:
+    ``placement.as_jax`` (host arrays to the device), ``placement
+    .candidates`` (the implementation table and the candidate build, which
+    JAX dispatches op by op), ``placement.greedy`` (dispatch of the jitted
+    greedy and σ) and ``placement.wait`` (the host blocks on σ). The
+    greedy's counts add to the ``placement.greedy_iters`` and
+    ``placement.greedy_rescores`` counters, and the effective ``k`` is
+    published on the ``placement.candidate_k`` gauge. With tracing off
+    nothing reads the counts.
     """
     if algo != "egp":
         raise ValueError(f"sparse path implements 'egp' only, got {algo!r}")
@@ -351,17 +362,24 @@ def evaluate_sparse(instances: Sequence[PIESInstance], algo: str = "egp",
     values, xs = [], []
     tracer = obs.get_tracer()
     for inst in instances:
-        ji = inst.as_jax()
-        table = impl_table_np(inst.sm_service, inst.S)
-        cand_idx, cand_q = qos_candidates_from_instance(
-            ji, table, k, use_kernel=use_kernel)
+        with obs.span("placement.as_jax"):
+            ji = inst.as_jax()
+        with obs.span("placement.candidates"):
+            table = impl_table_np(inst.sm_service, inst.S)
+            cand_idx, cand_q = qos_candidates_from_instance(
+                ji, table, k, use_kernel=use_kernel)
         if tracer is not None:
             tracer.metrics.gauge("placement.candidate_k").set(
                 int(cand_idx.shape[1]))
         mi = int(max_iters) if max_iters is not None else inst.P + 1
-        v, x = sparse_evaluator(mi, use_kernel)(
-            cand_idx, cand_q, ji.u_edge, ji.sm_service, ji.sm_r, ji.R)
-        values.append(float(v))
+        with obs.span("placement.greedy"):
+            v, x, n_iters, n_rescores = sparse_evaluator(mi, use_kernel)(
+                cand_idx, cand_q, ji.u_edge, ji.sm_service, ji.sm_r, ji.R)
+        with obs.span("placement.wait"):
+            values.append(float(v))
+        if tracer is not None:
+            tracer.count("placement.greedy_iters", int(n_iters))
+            tracer.count("placement.greedy_rescores", int(n_rescores))
         xs.append(x)
     return np.asarray(values, np.float64), xs
 
