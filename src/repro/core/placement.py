@@ -22,7 +22,11 @@ the serving control plane uses):
   Algorithm 3 decisions driven from a top-k ``(user, candidate)`` pair set
   (:mod:`repro.core.candidates`), all edges advanced in lock-step by one
   joint ``lax.while_loop``; state is O(U·k + E·P) instead of the dense
-  path's O(E·U·P), which is what makes 10⁵–10⁶-user ticks feasible.
+  path's O(E·U·P), which is what makes 10⁵–10⁶-user ticks feasible. The
+  users are grouped by ``(edge, service)`` once per call, and each pick's
+  re-score visits only the group of its edge and service, the only users
+  whose benefits or ``satisfied`` it can change: per-pick work scales with
+  those groups, not with U.
 """
 from __future__ import annotations
 
@@ -50,6 +54,10 @@ __all__ = [
 #: :func:`agp_np` and :func:`_agp_one_edge` — they can never disagree on
 #: which placements are feasible.
 FEASIBILITY_TOL = 1e-6
+
+#: Users of one ``(edge, service)`` group that the sparse greedy's re-score
+#: visits at once, at every edge together; a larger group takes more chunks.
+GROUP_CHUNK = 8
 
 #: Decision-ledger hook. ``repro.obs.ledger.enable_ledger()`` installs a
 #: :class:`~repro.obs.ledger.DecisionLedger` here (the core never imports
@@ -434,13 +442,24 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
     (:mod:`repro.kernels.qos_matrix`); the default uses the identical jnp
     reduction (interpret-mode Pallas inside a while_loop is slow on CPU).
 
+    Every candidate of a user implements that user's own service, so a
+    pick ``p*`` at edge ``e`` changes benefits (lines 15–16) and
+    ``satisfied`` (lines 18–19) only through the users of ``e`` who
+    request ``svc(p*)``. The users are sorted once by ``(edge, service)``,
+    each such group a slice of the sorted order, and the re-score visits
+    each placing edge's group, :data:`GROUP_CHUNK` users at a time at
+    every edge together, as many chunks as the largest group needs; a
+    per-edge count of unsatisfied users is carried for the stop test. The
+    sums are over the same float32 terms as over every pair; only their
+    order differs.
+
     The program's phases carry ``jax.named_scope`` tags, so a profiler
     trace (or the compiled HLO's ``op_name`` metadata) attributes device
-    time to them: ``greedy.init`` (the pair layout and the initial benefit
-    scatters), and per iteration ``greedy.pick`` (lines 11–14 and the
-    per-user gathers of the pick), ``greedy.rescore`` (the ``lax.cond`` of
-    lines 15–19) and ``greedy.stop_test`` (line 17's ``considered`` update,
-    the per-edge unsatisfied count and line 20).
+    time to them: ``greedy.init`` (the grouping, the pair layout and the
+    initial benefit scatters), and per iteration ``greedy.pick`` (lines
+    11–14), ``greedy.rescore`` (the ``lax.cond`` of lines 15–19 over the
+    placing edges' groups) and ``greedy.stop_test`` (line 17's
+    ``considered`` update and line 20).
 
     ``with_trace=True`` additionally returns a per-iteration decision
     trace for the observability ledger: ``[max_iters, E]`` arrays of the
@@ -454,7 +473,8 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
 
     Returns ``x [E, P]`` bool (or ``(x, trace_dict)`` with
     ``with_trace=True``; the trace also holds the loop's counts
-    ``n_iters`` and ``n_rescores``).
+    ``n_iters``, ``n_rescores`` and ``n_group_users``, the users the
+    re-scores visited: the placed picks' group sizes, summed).
     """
     x, info = _egp_place_sparse(
         cand_idx, cand_q, u_edge, sm_service, sm_r, R, max_iters=max_iters,
@@ -465,15 +485,19 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
 def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
                       max_iters: int, use_kernel: bool, with_trace: bool):
     """:func:`egp_place_sparse_jax`'s program, returning ``(x, info)``:
-    ``info["n_iters"]`` (iterations run) and ``info["n_rescores"]``
-    (iterations in which some edge placed and the re-score ran), int32
-    scalars, plus the decision trace's arrays with ``with_trace``."""
+    ``info["n_iters"]`` (iterations run), ``info["n_rescores"]``
+    (iterations in which some edge placed and the re-score ran) and
+    ``info["n_group_users"]`` (users the re-scores visited: the sizes of
+    the placed picks' ``(edge, service)`` groups, summed over the loop),
+    int32 scalars, plus the decision trace's arrays with ``with_trace``."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
     U, K = cand_q.shape
     P = sm_service.shape[0]
     E = R.shape[0]
+    G = max(1, min(GROUP_CHUNK, U))
     NEG = jnp.float32(-1e30)
 
     def scatter_ep(w):
@@ -483,21 +507,48 @@ def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
         return out[:, :P]
 
     with jax.named_scope("greedy.init"):
+        svc = sm_service.astype(jnp.int32)
+        p_arange = jnp.arange(P)
+        e_arange = jnp.arange(E)
+        # A user's candidates all implement its own service, so the largest
+        # candidate index (valid wherever any is) names it; a user with no
+        # candidate gets service P and so belongs to no group. Users sorted
+        # by the key edge·(P + 1) + service hold each (edge, service) group
+        # as one slice. Keys fit int32 while E·(P + 1) < 2³¹.
+        idx_t = cand_idx.T.astype(jnp.int32)
+        top = idx_t.max(axis=0)
+        svc_u = jnp.where(top >= 0, svc[jnp.clip(top, 0, None)], P)
+        key = u_edge.astype(jnp.int32) * (P + 1) + svc_u
+        skey, perm = lax.sort((key, jnp.arange(U, dtype=jnp.int32)),
+                              num_keys=1)
         # Pairs are held candidate-major, [K, U], so that U lies along the
         # TPU's 128-lane axis. Held [U, K], every gather and scatter over
         # them pads K to 128 lanes and takes minutes to compile at U = 10⁶.
-        idx_t = cand_idx.T
-        valid = idx_t >= 0
+        idx_s = idx_t[:, perm]
+        valid = idx_s >= 0
         # Sentinel column P absorbs scatters from padded candidate slots.
-        col = jnp.where(valid, idx_t, P).astype(jnp.int32)
-        qpair = jnp.where(valid, cand_q.T, 0.0).astype(jnp.float32)
-        erow = u_edge.astype(jnp.int32)
+        col = jnp.where(valid, idx_s, P)
+        qpair = jnp.where(valid, cand_q.T[:, perm], 0.0).astype(jnp.float32)
+        erow = skey // (P + 1)
         sm_r = sm_r.astype(jnp.float32)
-        p_arange = jnp.arange(P)
-        e_arange = jnp.arange(E)
         relevant = scatter_ep(valid.astype(jnp.float32)) > 0.0  # [E, P]
         # lines 3–6: v[(s,m)] = Σ_{u∈U_e} Q(u,s_u,m)
         v0 = scatter_ep(qpair)
+        # Each implementation's rank among its service's: within a group
+        # (one service) a rank names a candidate's column, so the loop
+        # keeps ranks (−1 where padded) and not columns.
+        rank = ((svc[None, :] == svc[:, None])
+                & (p_arange[None, :] < p_arange[:, None])).sum(axis=1)
+        rank = rank.astype(jnp.int32)
+        rk = jnp.append(rank, -1)[col]
+        n_blocks = rank.max() // K + 1   # blocks of K ranks a service spans
+        # start of each key's slice in the sorted order: group (e, s) is
+        # [start[e·(P+1) + s], start[e·(P+1) + s + 1])
+        start = jnp.cumsum(jnp.zeros(E * (P + 1) + 1, jnp.int32)
+                           .at[key + 1].add(1))
+        # users at each edge, those without a candidate among them
+        e_lo = start[jnp.arange(E + 1) * (P + 1)]
+        n_unsat0 = e_lo[1:] - e_lo[:-1]
 
     def masked_argmax(v, cand):
         if use_kernel:
@@ -506,6 +557,72 @@ def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
                                    use_kernel=True)
             return jnp.clip(idx, 0, None)
         return jnp.argmax(jnp.where(cand, v, NEG), axis=1)
+
+    def skip(arg):
+        return arg + (jnp.zeros(E, jnp.float32),)   # no gains
+
+    def rescore(arg, hit, place, considered):
+        """Lines 15–19 at every placing edge, over the users of its
+        ``(edge, svc(p*))`` group alone: only they can have a pair with a
+        sibling of p*, and only their ``satisfied`` can change. Groups are
+        visited G users at a time, as many chunks as the largest needs."""
+        if U == 0:      # no candidate, so no pick is ever placed
+            return skip(arg)
+        v, satisfied, n_unsat, n_group, best_u = arg
+        s_star = jnp.where(hit, svc, 0).sum(axis=1)
+        r_star = jnp.where(hit, rank, 0).sum(axis=1)
+        bounds = start[(e_arange * (P + 1) + s_star)[:, None]
+                       + jnp.arange(2)]                          # [E, 2]
+        lo = bounds[:, 0]
+        size = jnp.where(place, bounds[:, 1] - lo, 0)
+        n_chunks = (size.max() + G - 1) // G
+        slot = jnp.arange(G)[:, None]
+
+        def chunk(j, carry):
+            diff, satisfied, n_unsat, best_u, gain = carry
+            first = lo + j * G
+            base = jnp.clip(first, 0, U - G)    # window kept in bounds
+            pos = base[None, :] + slot                            # [G, E]
+            member = (pos >= first) & (pos < lo + size)
+            rk_m = rk[:, pos]                                     # [K, G, E]
+            q_m = qpair[:, pos]
+            sat_m = satisfied[pos]
+            qstar = jnp.where(rk_m == r_star, q_m, 0.0).sum(axis=0)
+            unsat = member & ~sat_m
+            # lines 15–16: v[p] = Σ_unsat (Q[u,p] − Q[u,p*]), by rank
+            w = jnp.where(unsat, q_m - qstar, 0.0)
+
+            def block(b, diff):
+                r = b * K + jnp.arange(K)
+                by_rank = jnp.where(rk_m[None] == r[:, None, None, None],
+                                    w[None], 0.0).sum(axis=(1, 2))  # [K, E]
+                of_rank = rank[None, :] == r[:, None]               # [K, P]
+                return diff + jnp.where(of_rank[:, None, :],
+                                        by_rank[:, :, None], 0.0).sum(axis=0)
+
+            diff = lax.fori_loop(0, n_blocks, block, diff)
+            # lines 18–19: users fully satisfied by (s*, m*)
+            newly = unsat & (qstar >= 1.0 - 1e-6)
+            at = jnp.where(member, pos, U)     # U: out of bounds, dropped
+            satisfied = satisfied.at[at].set(sat_m | newly, mode="drop")
+            n_unsat = n_unsat - newly.sum(axis=0)
+            if with_trace:
+                # exact marginal per placed pick, booked before best_u moves
+                b_m = best_u[pos]
+                gain = gain + jnp.where(member, jnp.maximum(qstar - b_m, 0.0),
+                                        0.0).sum(axis=0)
+                best_u = best_u.at[at].set(jnp.maximum(b_m, qstar),
+                                           mode="drop")
+            return diff, satisfied, n_unsat, best_u, gain
+
+        diff, satisfied, n_unsat, best_u, gain = lax.fori_loop(
+            0, n_chunks, chunk,
+            (jnp.zeros((E, P), jnp.float32), satisfied, n_unsat, best_u,
+             jnp.zeros(E, jnp.float32)))
+        sib = (svc[None, :] == s_star[:, None]) & ~considered & ~hit \
+            & relevant
+        v = jnp.where(place[:, None] & sib, diff, v)
+        return v, satisfied, n_unsat, n_group + size.sum(), best_u, gain
 
     def cond(state):
         # `it` and `done` sit at fixed positions in both carry layouts
@@ -516,35 +633,36 @@ def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
 
     def body(state):
         if with_trace:
-            (x, v, considered, satisfied, remaining, it, n_rescore,
-             best_u, tr, done) = state
+            (x, v, considered, satisfied, remaining, it, n_rescore, n_group,
+             n_unsat, best_u, tr, done) = state
         else:
-            x, v, considered, satisfied, remaining, it, n_rescore, done = \
-                state
+            (x, v, considered, satisfied, remaining, it, n_rescore, n_group,
+             n_unsat, done) = state
+            best_u = jnp.zeros(0, jnp.float32)   # no gains to book
         with jax.named_scope("greedy.pick"):
             cand = relevant & ~considered
             any_cand = cand.any(axis=1)                       # [E]
             p_star = masked_argmax(v, cand)                   # [E] line 11
-            fits = sm_r[p_star] <= remaining + FEASIBILITY_TOL
+            # one-hot of each edge's pick: [E, P] selects, not gathers or
+            # scatters, read and write the pick's entries
+            hit = p_arange[None, :] == p_star[:, None]
+            cost = jnp.where(hit, sm_r, 0.0).sum(axis=1)
+            fits = cost <= remaining + FEASIBILITY_TOL
             place = fits & any_cand & ~done                   # lines 12–14
             active = any_cand & ~done  # edges actually picking this iter
-            benefit = jnp.take_along_axis(v, p_star[:, None], 1)[:, 0]
-            x = x.at[e_arange, p_star].set(x[e_arange, p_star] | place)
-            remaining = remaining - jnp.where(place, sm_r[p_star], 0.0)
+            benefit = jnp.where(hit, v, NEG).max(axis=1)
+            x = x | (hit & place[:, None])
+            remaining = remaining - jnp.where(place, cost, 0.0)
 
-            pstar_u = p_star[erow]                        # [U] p* of u's edge
-            place_u = place[erow]
-            # Q(u, s_u, m*) per user — 0 unless p* is one of u's candidates.
-            qstar_u = jnp.where(col == pstar_u[None, :], qpair,
-                                0.0).sum(axis=0)
-
+        with jax.named_scope("greedy.rescore"):
+            placed_any = place.any()
+            v, satisfied, n_unsat, n_group, best_u, gain_e = jax.lax.cond(
+                placed_any,
+                functools.partial(rescore, hit=hit, place=place,
+                                  considered=considered),
+                skip, (v, satisfied, n_unsat, n_group, best_u))
+            n_rescore = n_rescore + placed_any.astype(jnp.int32)
             if with_trace:
-                # exact marginal per placed pick, booked before best_u moves
-                imp_u = jnp.where(place_u,
-                                  jnp.maximum(qstar_u - best_u, 0.0), 0.0)
-                gain_e = jnp.zeros(E, jnp.float32).at[erow].add(imp_u)
-                best_u = jnp.where(place_u, jnp.maximum(best_u, qstar_u),
-                                   best_u)
                 t_pick, t_place, t_ben, t_gain, t_rem, t_ncand = tr
                 tr = (
                     t_pick.at[it].set(jnp.where(active, p_star, -1)),
@@ -554,33 +672,8 @@ def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
                     t_rem.at[it].set(remaining),
                     t_ncand.at[it].set(cand.sum(axis=1).astype(jnp.int32)),
                 )
-
-        def rescore(arg):
-            # lines 15–16: v[p] = Σ_unsat (Q[u,p] − Q[u,p*]) for siblings
-            # of s*. O(U·k) pair scatter — only run when something placed.
-            v, satisfied = arg
-            unsat_u = place_u & ~satisfied
-            w = jnp.where(unsat_u[None, :] & valid,
-                          qpair - qstar_u[None, :], 0.0)
-            diff = scatter_ep(w)
-            sib = (sm_service[None, :] == sm_service[p_star][:, None]) \
-                & ~considered & (p_arange[None, :] != p_star[:, None]) \
-                & relevant
-            v = jnp.where(place[:, None] & sib, diff, v)
-            # lines 18–19: users fully satisfied by (s*, m*)
-            satisfied = satisfied | (place_u & (qstar_u >= 1.0 - 1e-6))
-            return v, satisfied
-
-        with jax.named_scope("greedy.rescore"):
-            placed_any = place.any()
-            v, satisfied = jax.lax.cond(placed_any, rescore, lambda a: a,
-                                        (v, satisfied))
-            n_rescore = n_rescore + placed_any.astype(jnp.int32)
         with jax.named_scope("greedy.stop_test"):
-            considered = considered.at[e_arange, p_star].set(
-                considered[e_arange, p_star] | any_cand)  # line 17
-            n_unsat = jnp.zeros(E, jnp.int32).at[erow].add(
-                (~satisfied).astype(jnp.int32))
+            considered = considered | (hit & any_cand[:, None])  # line 17
             all_sat = n_unsat == 0
             all_cons = (considered | ~relevant).all(axis=1)
             # line 20 — same stop conditions (and tolerances) as
@@ -588,15 +681,16 @@ def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
             done = done | ~any_cand | (remaining <= 1e-6) | all_sat \
                 | all_cons
             it = it + 1
+        core = (x, v, considered, satisfied, remaining, it, n_rescore,
+                n_group, n_unsat)
         if with_trace:
-            return (x, v, considered, satisfied, remaining, it, n_rescore,
-                    best_u, tr, done)
-        return x, v, considered, satisfied, remaining, it, n_rescore, done
+            return core + (best_u, tr, done)
+        return core + (done,)
 
     with jax.named_scope("greedy.init"):
         init_core = (jnp.zeros((E, P), bool), v0, jnp.zeros((E, P), bool),
                      jnp.zeros(U, bool), R.astype(jnp.float32),
-                     jnp.int32(0), jnp.int32(0))
+                     jnp.int32(0), jnp.int32(0), jnp.int32(0), n_unsat0)
         if with_trace:
             tr0 = (jnp.full((max_iters, E), -1, jnp.int32),
                    jnp.zeros((max_iters, E), bool),
@@ -609,9 +703,10 @@ def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
         else:
             init = init_core + (jnp.zeros(E, bool),)
     out = jax.lax.while_loop(cond, body, init)
-    info = {"n_iters": out[5], "n_rescores": out[6]}
+    info = {"n_iters": out[5], "n_rescores": out[6],
+            "n_group_users": out[7]}
     if with_trace:
-        tr = out[8]
+        tr = out[10]
         info.update(pick=tr[0], placed=tr[1], benefit=tr[2], gain=tr[3],
                     remaining=tr[4], n_candidates=tr[5])
     return out[0], info

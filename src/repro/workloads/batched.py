@@ -314,8 +314,9 @@ def evaluate_batch(batch, algo: str = "egp", max_iters: int = 512):
 def sparse_evaluator(max_iters: int, use_kernel: bool):
     """The jitted sparse EGP tick over top-k candidate pairs:
     ``(cand_idx, cand_q, u_edge, sm_service, sm_r, R) -> (σ, x, n_iters,
-    n_rescores)``, the last two the greedy loop's counts (int32 scalars:
-    iterations, and iterations that ran the re-score)."""
+    n_rescores, n_group_users)``, the last three the greedy loop's counts
+    (int32 scalars: iterations, iterations that ran the re-score, and the
+    users those re-scores visited)."""
     import jax
 
     from repro.core.placement import _egp_place_sparse, sigma_sparse_jnp
@@ -325,7 +326,7 @@ def sparse_evaluator(max_iters: int, use_kernel: bool):
             cand_idx, cand_q, u_edge, sm_service, sm_r, R,
             max_iters=max_iters, use_kernel=use_kernel, with_trace=False)
         return (sigma_sparse_jnp(cand_idx, cand_q, u_edge, x), x,
-                info["n_iters"], info["n_rescores"])
+                info["n_iters"], info["n_rescores"], info["n_group_users"])
 
     return jax.jit(run)
 
@@ -348,8 +349,9 @@ def evaluate_sparse(instances: Sequence[PIESInstance], algo: str = "egp",
     .candidates`` (the implementation table and the candidate build, which
     JAX dispatches op by op), ``placement.greedy`` (dispatch of the jitted
     greedy and σ) and ``placement.wait`` (the host blocks on σ). The
-    greedy's counts add to the ``placement.greedy_iters`` and
-    ``placement.greedy_rescores`` counters, and the effective ``k`` is
+    greedy's counts add to the ``placement.greedy_iters``,
+    ``placement.greedy_rescores`` and ``placement.greedy_group_users``
+    (users the re-scores visited) counters, and the effective ``k`` is
     published on the ``placement.candidate_k`` gauge. With tracing off
     nothing reads the counts.
     """
@@ -373,13 +375,15 @@ def evaluate_sparse(instances: Sequence[PIESInstance], algo: str = "egp",
                 int(cand_idx.shape[1]))
         mi = int(max_iters) if max_iters is not None else inst.P + 1
         with obs.span("placement.greedy"):
-            v, x, n_iters, n_rescores = sparse_evaluator(mi, use_kernel)(
+            v, x, n_iters, n_rescores, n_group = sparse_evaluator(
+                mi, use_kernel)(
                 cand_idx, cand_q, ji.u_edge, ji.sm_service, ji.sm_r, ji.R)
         with obs.span("placement.wait"):
             values.append(float(v))
         if tracer is not None:
             tracer.count("placement.greedy_iters", int(n_iters))
             tracer.count("placement.greedy_rescores", int(n_rescores))
+            tracer.count("placement.greedy_group_users", int(n_group))
         xs.append(x)
     return np.asarray(values, np.float64), xs
 

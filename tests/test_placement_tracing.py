@@ -41,13 +41,15 @@ def test_compiled_tick_carries_phase_scopes(tick):
     inst, args = tick
     text = sparse_evaluator(inst.P + 1, False).lower(*args).compile() \
         .as_text()
-    ops = re.findall(r"= \S+ (scatter|gather)\(.*op_name=\"([^\"]*)\"", text)
+    ops = re.findall(r"= \S+ ([\w-]+)\(.*op_name=\"([^\"]*)\"", text)
     scopes = {phase for _, name in ops for phase in PHASES
               if f"/{phase}/" in name}
     assert scopes == set(PHASES), sorted(scopes)
-    # the re-score's pair scatter keeps its scope inside the cond branch
-    assert any(kind == "scatter" and "/greedy.rescore/" in name
-               and "branch" in name for kind, name in ops)
+    # the re-score's gathers of its groups' users and its `satisfied`
+    # scatter keep their scope inside the cond branch
+    for op in ("gather", "scatter"):
+        assert any(kind == op and "/greedy.rescore/" in name
+                   and "branch" in name for kind, name in ops), op
 
 
 def test_counters_match_the_decision_trace(tick):
@@ -73,11 +75,12 @@ def test_counts_and_tracing_change_no_answer(tick):
                                            with_trace=True)
     assert np.array_equal(np.asarray(x_plain), np.asarray(x_traced))
     # the jitted tick returns the same placement and the loop's counts
-    _, x_run, n_iters, n_rescores = sparse_evaluator(inst.P + 1,
-                                                     False)(*args)
+    _, x_run, n_iters, n_rescores, n_group = sparse_evaluator(
+        inst.P + 1, False)(*args)
     assert np.array_equal(np.asarray(x_run), np.asarray(x_plain))
     assert int(n_iters) == int(trace["n_iters"])
     assert int(n_rescores) == int(trace["n_rescores"])
+    assert int(n_group) == int(trace["n_group_users"])
     v_off, x_off = evaluate_sparse([inst])
     obs.enable()
     v_on, x_on = evaluate_sparse([inst])
