@@ -98,7 +98,7 @@ def topk_candidates_np(inst: PIESInstance, k: Optional[int] = None,
     model index, matching ``lax.top_k``'s first-occurrence order)."""
     if Q is None:
         Q = qos_matrix_np(inst)
-    table = impl_table_np(inst.sm_service, inst.S)
+    table = impl_table_np(inst.sm_service, inst.n_service_ids)
     M = table.shape[1]
     k_eff = M if k is None else min(int(k), M)
     cand = table[inst.u_service]                       # [U, M]

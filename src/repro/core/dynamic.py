@@ -12,6 +12,15 @@ all at once." This module implements that extension:
   implementations get a stickiness bonus in the benefit map, trading a
   little instantaneous QoS for amortized stability.
 
+Two paths make the same placement, chosen by the tick's size U·P.
+Below :data:`DEVICE_MIN_CELLS` the step runs :func:`_egp_with_bias`, a
+float64 host loop over a dense ``[U, P]`` QoS matrix, which serves the
+small served scenarios; at and above it the step runs the sparse greedy
+on the device with the same bias
+(:func:`repro.workloads.batched.place_on_device`), in float32, and the
+resident set stays on the device between ticks. :func:`_egp_with_bias`
+is the float64 reference the device path is tested against.
+
 ``evaluate_horizon`` compares three policies over a tick sequence:
 ``static`` (place once on tick 0), ``greedy`` (EGP from scratch every
 tick, pays switching), ``hysteresis`` (ours).
@@ -29,7 +38,15 @@ from . import placement as _placement
 from .placement import FEASIBILITY_TOL, egp_np, sigma_upper_bound_np
 from .scheduling import sigma_np
 
-__all__ = ["DynamicPlacer", "evaluate_horizon"]
+__all__ = ["DynamicPlacer", "evaluate_horizon", "DEVICE_MIN_CELLS"]
+
+#: U·P of a tick from which :class:`DynamicPlacer` places on the device.
+#: Above the served scenarios' ticks and the tests' horizons (under 6·10⁴
+#: cells) and far below a fleet's (10⁸ and more);
+#: ``benchmarks/placer_crossover.py`` measures where the two paths' step
+#: times cross on the host that serves: on a TPU v5e host, between
+#: 115,400 cells (host step 25 ms, device 29 ms) and 265,500 (72 ms, 30 ms).
+DEVICE_MIN_CELLS = 200_000
 
 
 def _egp_with_bias(inst: PIESInstance, Q: np.ndarray,
@@ -107,6 +124,17 @@ def _egp_with_bias(inst: PIESInstance, Q: np.ndarray,
 
 @dataclasses.dataclass
 class DynamicPlacer:
+    """EGP with hysteresis, tick after tick, over a carried resident set.
+
+    A tick of U users over P implementations places on the host, by
+    :func:`_egp_with_bias` in float64, while U·P is under
+    :data:`DEVICE_MIN_CELLS`, and from there on the device, by the sparse
+    greedy with the same bias in float32
+    (:func:`repro.workloads.batched.place_on_device`). Both make the same
+    picks but where two benefits are within float32 rounding of each
+    other. The resident set is kept where the last step ran.
+    """
+
     switching_cost: float = 2.0   # QoS units per newly-loaded model
     stickiness: float = 3.0       # benefit bonus for resident models
 
@@ -128,11 +156,21 @@ class DynamicPlacer:
         ``self.stickiness`` is read afresh every step, so a feedback
         controller (:class:`repro.tuning.controller.FeedbackPlacer`) can
         adapt the hysteresis online between ticks.
+
+        ``Q`` is the tick's ``qos_matrix_np(inst)``, computed here if not
+        given; the device path (U·P at or above :data:`DEVICE_MIN_CELLS`)
+        computes the QoS of each user's eligible implementations itself
+        and does not read it. The decision ledger
+        (``placement._DECISION_SINK``) is fed only on the host path,
+        below the crossover.
         """
-        if Q is None:
-            Q = qos_matrix_np(inst)
         if self._resident is None:
             self._resident = np.zeros((inst.E, inst.P), dtype=bool)
+        if inst.U * inst.P >= DEVICE_MIN_CELLS:
+            return self._step_device(inst)
+        if Q is None:
+            Q = qos_matrix_np(inst)
+        self._resident = np.asarray(self._resident)
         x = _egp_with_bias(inst, Q, self._resident, self.stickiness)
         self.new_loads = x & ~self._resident
         self.evicted = self._resident & ~x
@@ -146,6 +184,15 @@ class DynamicPlacer:
                      sigma_bound=sigma_upper_bound_np(inst, Q))
         self._resident = x
         return x, value, loads
+
+    def _step_device(self, inst: PIESInstance):
+        from repro.workloads.batched import place_on_device
+
+        out = place_on_device(inst, self._resident, self.stickiness,
+                              self.switching_cost)
+        self.new_loads, self.evicted = out["new_loads"], out["evicted"]
+        self._resident = out["resident"]
+        return out["x"], float(out["value"]), int(out["loads"])
 
 
 def evaluate_horizon(instances: Union[str, List[PIESInstance]],

@@ -77,6 +77,12 @@ class PIESInstance:
     def S(self) -> int:
         return int(self.sm_service.max()) + 1 if self.P else 0
 
+    @property
+    def n_service_ids(self) -> int:
+        """One more than the largest service id a user or an implementation
+        names (:attr:`S` counts up to the last implemented service only)."""
+        return max(self.S, int(self.u_service.max(initial=-1)) + 1)
+
     def covered_counts(self) -> np.ndarray:
         """``|U_e|`` for every edge cloud ``e`` (Eq. 5/6 sharing factor)."""
         return np.bincount(self.u_edge, minlength=self.E).astype(np.float64)
@@ -103,18 +109,37 @@ class PIESInstance:
             have = np.unique(self.sm_service)
             assert np.all(np.isin(req, have)), "user requests unknown service"
 
-    def as_jax(self):
-        """Return a :class:`JaxInstance` pytree mirror of this instance."""
+    def as_jax(self, n_slots: Optional[int] = None):
+        """Return a :class:`JaxInstance` pytree mirror of this instance.
+
+        With ``n_slots``, the users are padded to that many slots. A padded
+        slot sits at edge ``E``, past every real edge, with α = δ = 0 and
+        service :attr:`n_service_ids`, which no implementation implements:
+        given an implementation table with that row, it has no candidate
+        pair and is counted at no edge.
+        """
         import jax.numpy as jnp
 
         counts = self.covered_counts()
+        users = [self.u_alpha, self.u_delta, self.u_service, self.u_edge,
+                 counts[self.u_edge] / self.K[self.u_edge],
+                 counts[self.u_edge] / self.W[self.u_edge]]
+        if n_slots is not None:
+            if n_slots < self.U:
+                raise ValueError(f"{n_slots} user slots cannot hold "
+                                 f"{self.U} users")
+            fills = (0.0, 0.0, self.n_service_ids, self.E, 0.0, 0.0)
+            users = [np.concatenate([a, np.full(n_slots - self.U, f,
+                                                np.asarray(a).dtype)])
+                     for a, f in zip(users, fills)]
+        alpha, delta, service, edge, share_k, share_w = users
         return JaxInstance(
-            u_alpha=jnp.asarray(self.u_alpha, jnp.float32),
-            u_delta=jnp.asarray(self.u_delta, jnp.float32),
-            u_service=jnp.asarray(self.u_service, jnp.int32),
-            u_edge=jnp.asarray(self.u_edge, jnp.int32),
-            u_share_k=jnp.asarray(counts[self.u_edge] / self.K[self.u_edge], jnp.float32),
-            u_share_w=jnp.asarray(counts[self.u_edge] / self.W[self.u_edge], jnp.float32),
+            u_alpha=jnp.asarray(alpha, jnp.float32),
+            u_delta=jnp.asarray(delta, jnp.float32),
+            u_service=jnp.asarray(service, jnp.int32),
+            u_edge=jnp.asarray(edge, jnp.int32),
+            u_share_k=jnp.asarray(share_k, jnp.float32),
+            u_share_w=jnp.asarray(share_w, jnp.float32),
             sm_service=jnp.asarray(self.sm_service, jnp.int32),
             sm_acc=jnp.asarray(self.sm_acc, jnp.float32),
             sm_k=jnp.asarray(self.sm_k, jnp.float32),

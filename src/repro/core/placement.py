@@ -483,13 +483,20 @@ def egp_place_sparse_jax(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
 
 
 def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
-                      max_iters: int, use_kernel: bool, with_trace: bool):
+                      max_iters: int, use_kernel: bool, with_trace: bool,
+                      bias=None):
     """:func:`egp_place_sparse_jax`'s program, returning ``(x, info)``:
     ``info["n_iters"]`` (iterations run), ``info["n_rescores"]``
     (iterations in which some edge placed and the re-score ran) and
     ``info["n_group_users"]`` (users the re-scores visited: the sizes of
     the placed picks' ``(edge, service)`` groups, summed over the loop),
-    int32 scalars, plus the decision trace's arrays with ``with_trace``."""
+    int32 scalars, plus the decision trace's arrays with ``with_trace``.
+
+    ``bias [E, P]`` (float32), where given, is added to every relevant
+    implementation's benefit: to ``v0`` and to each sibling's re-scored
+    benefit, as :func:`repro.core.dynamic._egp_with_bias` adds its
+    stickiness bonus to resident implementations. Without it the program
+    is the unbiased one."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -534,6 +541,8 @@ def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
         relevant = scatter_ep(valid.astype(jnp.float32)) > 0.0  # [E, P]
         # lines 3–6: v[(s,m)] = Σ_{u∈U_e} Q(u,s_u,m)
         v0 = scatter_ep(qpair)
+        if bias is not None:
+            v0 = v0 + jnp.where(relevant, bias, 0.0)
         # Each implementation's rank among its service's: within a group
         # (one service) a rank names a candidate's column, so the loop
         # keeps ranks (−1 where padded) and not columns.
@@ -543,7 +552,9 @@ def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
         rk = jnp.append(rank, -1)[col]
         n_blocks = rank.max() // K + 1   # blocks of K ranks a service spans
         # start of each key's slice in the sorted order: group (e, s) is
-        # [start[e·(P+1) + s], start[e·(P+1) + s + 1])
+        # [start[e·(P+1) + s], start[e·(P+1) + s + 1]). A user at edge E, a
+        # padded slot, has a key past the table: the scatter drops it, so
+        # it sorts last and is counted at no edge.
         start = jnp.cumsum(jnp.zeros(E * (P + 1) + 1, jnp.int32)
                            .at[key + 1].add(1))
         # users at each edge, those without a candidate among them
@@ -621,6 +632,8 @@ def _egp_place_sparse(cand_idx, cand_q, u_edge, sm_service, sm_r, R, *,
              jnp.zeros(E, jnp.float32)))
         sib = (svc[None, :] == s_star[:, None]) & ~considered & ~hit \
             & relevant
+        if bias is not None:
+            diff = diff + bias
         v = jnp.where(place[:, None] & sib, diff, v)
         return v, satisfied, n_unsat, n_group + size.sum(), best_u, gain
 

@@ -43,11 +43,16 @@ Two scale paths sit on top of the global-pad evaluator:
   :func:`repro.core.placement.egp_place_sparse_jax`, with memory O(U·k)
   instead of O(U·P·E). Exact vs the host path when ``k`` keeps every
   eligible implementation (the default).
+* **The biased placer** (:func:`place_on_device`) — the same sparse greedy
+  with :class:`repro.core.dynamic.DynamicPlacer`'s hysteresis, its users
+  padded to a size class (:func:`user_size_class`) so that a tick's user
+  count compiles nothing new, and its resident set kept on the device.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -68,6 +73,9 @@ __all__ = [
     "evaluate_batch",
     "evaluate_sparse",
     "sparse_evaluator",
+    "user_size_class",
+    "placer_program",
+    "place_on_device",
     "evaluate_host",
     "sweep",
 ]
@@ -367,7 +375,7 @@ def evaluate_sparse(instances: Sequence[PIESInstance], algo: str = "egp",
         with obs.span("placement.as_jax"):
             ji = inst.as_jax()
         with obs.span("placement.candidates"):
-            table = impl_table_np(inst.sm_service, inst.S)
+            table = impl_table_np(inst.sm_service, inst.n_service_ids)
             cand_idx, cand_q = qos_candidates_from_instance(
                 ji, table, k, use_kernel=use_kernel)
         if tracer is not None:
@@ -386,6 +394,134 @@ def evaluate_sparse(instances: Sequence[PIESInstance], algo: str = "egp",
             tracer.count("placement.greedy_group_users", int(n_group))
         xs.append(x)
     return np.asarray(values, np.float64), xs
+
+
+#: The largest user size class of :func:`user_size_class`; the classes
+#: below it shrink by √2 a step.
+USER_CLASS_TOP = 1_000_000
+
+
+def _user_class(j: int) -> int:
+    return math.ceil(USER_CLASS_TOP * 2.0 ** (-j / 2))
+
+
+def user_size_class(n: int) -> int:
+    """The user slots of a placer tick of ``n`` users: the least
+    ⌈10⁶ · 2^(−j/2)⌉, j a whole number, that holds ``n``. Geometric with
+    ratio √2, so a tick pads by at most 41 % and seven classes span
+    10⁵–10⁶; anchored at 10⁶, so that a 10⁶-user tick pads nothing. A pure
+    function of ``n``, so every tick of one size compiles the same
+    programs."""
+    if n <= 1:
+        return max(int(n), 0)
+    j = math.floor(2 * math.log2(USER_CLASS_TOP / n))
+    while _user_class(j) < n:
+        j -= 1
+    while _user_class(j + 1) >= n:
+        j += 1
+    return _user_class(j)
+
+
+@functools.lru_cache(maxsize=16)
+def placer_program(max_iters: int, use_kernel: bool):
+    """The jitted step of the biased placer, named ``place`` (``jit_place``
+    in a trace): ``(cand_idx, cand_q, u_edge, sm_service, sm_r, R,
+    resident, stickiness) ->`` a dict of ``x``, ``sigma``, ``loads``,
+    ``new_loads`` (``x & ~resident``), ``evicted`` (``resident & ~x``),
+    ``evictions`` and the greedy loop's counts. The sparse EGP runs with
+    the bias ``stickiness`` on every resident implementation, as
+    :func:`repro.core.dynamic._egp_with_bias` adds it."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.placement import _egp_place_sparse, sigma_sparse_jnp
+
+    def place(cand_idx, cand_q, u_edge, sm_service, sm_r, R, resident,
+              stickiness):
+        bias = jnp.where(resident, stickiness, 0.0).astype(jnp.float32)
+        x, info = _egp_place_sparse(
+            cand_idx, cand_q, u_edge, sm_service, sm_r, R,
+            max_iters=max_iters, use_kernel=use_kernel, with_trace=False,
+            bias=bias)
+        sigma = sigma_sparse_jnp(cand_idx, cand_q, u_edge, x)
+        new_loads = x & ~resident
+        evicted = resident & ~x
+        return {"x": x, "sigma": sigma,
+                "loads": new_loads.sum(dtype=jnp.int32),
+                "new_loads": new_loads, "evicted": evicted,
+                "evictions": evicted.sum(dtype=jnp.int32),
+                "n_iters": info["n_iters"],
+                "n_rescores": info["n_rescores"],
+                "n_group_users": info["n_group_users"]}
+
+    return jax.jit(place)
+
+
+def placer_inputs(inst: PIESInstance, n_slots: int):
+    """``(JaxInstance, impl table)`` of ``inst`` with its users padded to
+    ``n_slots`` (:meth:`PIESInstance.as_jax`). The table has a row, empty,
+    for the padded slots' service: they get no candidate pair, so they
+    change no decision and add nothing to σ."""
+    from repro.core.candidates import impl_table_np
+
+    return (inst.as_jax(n_slots),
+            impl_table_np(inst.sm_service, inst.n_service_ids + 1))
+
+
+def place_on_device(inst: PIESInstance, resident, stickiness: float,
+                    switching_cost: float) -> Dict:
+    """One hysteresis step of :class:`repro.core.dynamic.DynamicPlacer` on
+    the device: the users padded to :func:`user_size_class`, the candidate
+    QoS of every eligible implementation, then :func:`placer_program`.
+
+    ``resident`` is the ``[E, P]`` bool resident set, a device array or
+    one that is copied there. Returns the program's outputs on the host
+    (``x``, ``new_loads`` and ``evicted`` as NumPy bool arrays, the rest as
+    numbers), ``value`` (σ − switching_cost · loads, in float64) and, under
+    ``"resident"``, the placement ``x`` left on the device for the next
+    step. The Pallas kernels run on a TPU, their jnp references elsewhere.
+
+    With a tracer enabled its host phases are spans: ``placer.pad`` (the
+    padded user arrays to the device, and the implementation table),
+    ``placer.candidates`` (the candidate build), ``placer.greedy``
+    (dispatch of ``jit_place``) and ``placer.wait`` (the host blocks on
+    the results); and the step adds to the counters ``placement.loads``,
+    ``placement.evictions``, ``placement.pad_users``,
+    ``placement.user_slots``, ``placement.greedy_iters``,
+    ``placement.greedy_rescores`` and ``placement.greedy_group_users``.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from repro import obs
+    from repro.kernels.qos_matrix.ops import qos_candidates_from_instance
+
+    use_kernel = jax.default_backend() == "tpu"
+    n_slots = user_size_class(inst.U)
+    with obs.span("placer.pad"):
+        ji, table = placer_inputs(inst, n_slots)
+    with obs.span("placer.candidates"):
+        cand_idx, cand_q = qos_candidates_from_instance(
+            ji, table, None, use_kernel=use_kernel)
+    with obs.span("placer.greedy"):
+        out = placer_program(inst.P + 1, use_kernel)(
+            cand_idx, cand_q, ji.u_edge, ji.sm_service, ji.sm_r, ji.R,
+            jnp.asarray(resident, bool), jnp.float32(stickiness))
+    with obs.span("placer.wait"):
+        host = jax.device_get(out)
+    host["value"] = float(host["sigma"]) - switching_cost * int(host["loads"])
+    tracer = obs.get_tracer()
+    if tracer is not None:
+        tracer.count("placement.loads", int(host["loads"]))
+        tracer.count("placement.evictions", int(host["evictions"]))
+        tracer.count("placement.pad_users", n_slots - inst.U)
+        tracer.count("placement.user_slots", n_slots)
+        tracer.count("placement.greedy_iters", int(host["n_iters"]))
+        tracer.count("placement.greedy_rescores", int(host["n_rescores"]))
+        tracer.count("placement.greedy_group_users",
+                     int(host["n_group_users"]))
+    host["resident"] = out["x"]
+    return host
 
 
 def evaluate_host(instances: Sequence[PIESInstance],

@@ -101,6 +101,23 @@ def test_sparse_kernel_path_matches_ref_path():
     np.testing.assert_array_equal(np.asarray(x_ref[0]), np.asarray(x_k[0]))
 
 
+def test_users_of_an_unimplemented_last_service_get_no_candidate():
+    """The last service id loses its implementations: its users must get
+    no candidate, not the row of the last implemented service."""
+    import dataclasses
+
+    inst = synthetic_instance(400, n_edges=5, seed=3)
+    keep = inst.sm_service != inst.S - 1
+    inst = dataclasses.replace(inst, **{
+        f: getattr(inst, f)[keep]
+        for f in ("sm_service", "sm_acc", "sm_k", "sm_w", "sm_r")})
+    orphans = inst.u_service >= inst.S
+    assert orphans.any()
+    assert (topk_candidates_np(inst).cand_idx[orphans] == -1).all()
+    _, xs = evaluate_sparse([inst])
+    np.testing.assert_array_equal(np.asarray(xs[0]), egp_np(inst))
+
+
 def test_evaluate_sparse_rejects_non_egp():
     inst = synthetic_instance(50, seed=0)
     with pytest.raises(ValueError, match="egp"):

@@ -139,3 +139,17 @@ def test_sparse_egp_tick_compiles_with_kernel_at_1e5_users(one_chip,
         _shape(one_chip, (U,), jnp.int32),
         _shape(one_chip, (P,), jnp.int32), _shape(one_chip, (P,)),
         _shape(one_chip, (E,))).compile())
+
+
+def test_placer_step_compiles_with_kernel_at_1e5_users(one_chip, chip_branch):
+    """The hysteresis placer's jitted step: the biased greedy, σ and the
+    tick's loads and evictions over a resident set."""
+    from repro.workloads.batched import placer_program
+
+    U, E, P, k = 10**5, 100, 550, 10
+    _check(placer_program(P + 1, True).lower(
+        _shape(one_chip, (U, k), jnp.int32), _shape(one_chip, (U, k)),
+        _shape(one_chip, (U,), jnp.int32),
+        _shape(one_chip, (P,), jnp.int32), _shape(one_chip, (P,)),
+        _shape(one_chip, (E,)), _shape(one_chip, (E, P), jnp.bool_),
+        _shape(one_chip, ())).compile())
